@@ -1,9 +1,12 @@
 """Mesh-level policy experiment: bulk all-gather vs COPIFTv2 ring matmul.
 
 Runs in a subprocess with 8 host devices (the parent process must keep the
-default device count for the other benchmarks).  Reports wall time and the
-HLO collective op counts for both policies."""
+default device count for the other benchmarks).  The child is pinned to the
+CPU: it measures host virtual devices, and an accelerator, if any, already
+belongs to the parent.  Reports wall time and the HLO collective op counts
+for both policies."""
 import json
+import os
 import subprocess
 import sys
 
@@ -37,14 +40,14 @@ print(json.dumps(out))
 
 
 def run():
-    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "PYTHONPATH": "src"}
-    import os
-    env = {**os.environ, **env}
+    env = {**os.environ,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src"}
     res = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=300)
     if res.returncode != 0:
-        return [("collective_policy_error", 0.0, 0.0)]
+        raise RuntimeError(f"collective_policy child exited "
+                           f"{res.returncode}:\n{res.stderr[-2000:]}")
     data = json.loads(res.stdout.strip().splitlines()[-1])
     rows = []
     for pol, d in data.items():

@@ -27,14 +27,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.policy import ExecutionPolicy
 
-if hasattr(jax, "shard_map"):                   # jax >= 0.5
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                           # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
 def _bulk_kernel(x, w, axis: str):
     xg = jax.lax.all_gather(x, axis, axis=0, tiled=True)
     return xg @ w
@@ -75,9 +67,9 @@ def tp_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *,
     w_spec = w_spec or P(None, axis)
     out_spec = out_spec or P(None, axis)
     kern = _bulk_kernel if policy is not ExecutionPolicy.COPIFTV2 else _ring_kernel
-    fn = _shard_map(partial(kern, axis=axis), mesh=mesh,
-                    in_specs=(x_spec, w_spec), out_specs=out_spec,
-                    **{_CHECK_KW: False})
+    fn = jax.shard_map(partial(kern, axis=axis), mesh=mesh,
+                       in_specs=(x_spec, w_spec), out_specs=out_spec,
+                       check_vma=False)
     return fn(x, w)
 
 

@@ -14,8 +14,11 @@ from .kernel import flash_attention_kernel
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
-    """q: (B, Hq, S, D); k/v: (B, Hkv, S, D) — GQA expands KV heads."""
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """q: (B, Hq, S, D); k/v: (B, Hkv, S, D) — GQA expands KV heads.
+    ``interpret=None`` interprets the kernel unless the backend is a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
     if Hkv != Hq:

@@ -81,9 +81,10 @@ def _kernel(x_hbm, w_hbm, o_ref, xs, ws, acc, sx, sw, *,
         return ()
 
     # the calibrated schedule-interleave factor maps to K-loop unrolling (the
-    # FP thread retiring several queue pops per trip), clamped to the trip
-    # count so tiny problems still lower
-    jax.lax.fori_loop(0, nk, body, (), unroll=max(1, min(unroll, nk)))
+    # FP thread retiring several queue pops per trip).  The TPU lowering
+    # takes a loop either rolled or fully unrolled, so a factor that covers
+    # the trip count unrolls it fully and any smaller one keeps it rolled
+    jax.lax.fori_loop(0, nk, body, (), unroll=nk if unroll >= nk else 1)
     o_ref[...] = acc[...].astype(o_ref.dtype)
 
 

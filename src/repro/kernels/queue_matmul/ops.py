@@ -72,7 +72,7 @@ def queue_matmul(x: jax.Array, w: jax.Array, *,
                  depth_w: Optional[int] = None,
                  unroll: Optional[int] = None,
                  policy: Optional[ExecutionPolicy] = None,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """y = x @ w through the queue-pipelined kernel.
 
     ``policy`` overrides the depths: BASELINE falls back to the XLA matmul,
@@ -84,8 +84,11 @@ def queue_matmul(x: jax.Array, w: jax.Array, *,
     always win — ``depth`` pins both rings, ``depth_x``/``depth_w`` pin one
     each; in particular any explicit depth with ``policy`` unset runs the
     depth-honouring COPIFTv2 path (the pre-calibration behavior), never a
-    table policy that would discard it.
+    table policy that would discard it.  ``interpret=None`` interprets the
+    kernel unless the backend is a TPU.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     if depth is not None:
         depth_x = depth if depth_x is None else depth_x
         depth_w = depth if depth_w is None else depth_w

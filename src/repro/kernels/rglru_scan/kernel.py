@@ -18,13 +18,15 @@ def _kernel(a_ref, b_ref, h_out, h_scr, *, bt: int):
     def _():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    a = a_ref[0].astype(jnp.float32)          # (bt, bw)
-    bx = b_ref[0].astype(jnp.float32)
-
+    # each time step is read and written as a (1, bw) row slice of the refs:
+    # the TPU lowering has no dynamic index into a loaded value
     def step(t, _):
-        h = a[t] * h_scr[...] + bx[t]
+        row = pl.ds(t, 1)
+        a = a_ref[0, row, :].astype(jnp.float32)
+        bx = b_ref[0, row, :].astype(jnp.float32)
+        h = a * h_scr[...] + bx
         h_scr[...] = h
-        h_out[0, t, :] = h.astype(h_out.dtype)
+        h_out[0, row, :] = h.astype(h_out.dtype)
         return ()
 
     jax.lax.fori_loop(0, bt, step, ())
@@ -43,6 +45,6 @@ def rglru_scan_kernel(a, bx, *, bt: int, bw: int, interpret: bool) -> jax.Array:
         ],
         out_specs=pl.BlockSpec((1, bt, bw), lambda b, wi, ti: (b, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((B, T, w), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         interpret=interpret,
     )(a, bx)

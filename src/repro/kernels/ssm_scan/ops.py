@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,10 @@ from .kernel import ssm_scan_kernel
 
 @partial(jax.jit, static_argnames=("bt", "bd", "interpret"))
 def ssm_scan(x, dt, A, Bm, C, *, bt: int = 128, bd: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: Optional[bool] = None) -> jax.Array:
+    """``interpret=None`` interprets the kernel unless the backend is a TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, T, d = x.shape
     pt, pd = (-T) % bt, (-d) % bd
     if pt or pd:

@@ -75,18 +75,12 @@ def default_runconfig(shape: ShapeConfig, policy: Optional[str] = None,
                      analysis_mode=analysis)
 
 
-def _mesh_context(mesh: Mesh):
-    """Enter a mesh so PartitionSpec sharding constraints resolve: newer JAX
-    uses jax.set_mesh; on 0.4.x the Mesh itself is the context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
                rc: Optional[RunConfig] = None):
     """Build + lower the pjit step for one cell (traced inside a mesh
     context so PartitionSpec sharding constraints resolve)."""
     rc = rc or default_runconfig(shape)
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return _lower_cell_inner(cfg, shape, mesh, rc)
 
 

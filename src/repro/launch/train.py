@@ -18,6 +18,7 @@ from ..config import RunConfig, ShapeConfig
 from ..configs import ARCHS, get_config, get_reduced
 from ..models import init_model_params
 from ..runtime import FaultTolerantTrainer
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.width:

@@ -29,16 +29,38 @@ class ParamSpec:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
+#: leading axes that stack independent weights (a scanned layer stack, an
+#: expert bank): no part of any one weight's fan-in
+_STACK_AXES = ("layers", "experts")
+
+
+def _fan_in(spec: ParamSpec) -> int:
+    """Inputs summed into each output of the weight: its first axis after
+    any stacking axes, times ``head_dim`` where that axis is ``heads`` (an
+    attention output projection contracts both).  Too large a std makes
+    attention scores saturate the softmax, and the model then turns every
+    rounding into a different attended position."""
+    dims = list(zip(spec.shape, spec.axes))
+    while dims and dims[0][1] in _STACK_AXES:
+        dims.pop(0)
+    if len(dims) < 2:
+        return 1
+    fan_in = dims[0][0]
+    if dims[0][1] == "heads":
+        fan_in *= dims[1][0]
+    return fan_in
+
+
 def _init_leaf(key, spec: ParamSpec, dtype) -> jax.Array:
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, dtype)
     if spec.init == "embed":
-        return (jax.random.normal(key, spec.shape) * spec.scale).astype(dtype)
-    fan_in = spec.shape[0] if len(spec.shape) > 1 else 1
-    std = spec.scale / math.sqrt(max(fan_in, 1))
-    return (jax.random.normal(key, spec.shape) * std).astype(dtype)
+        return (jax.random.normal(key, spec.shape, dtype) * spec.scale
+                ).astype(dtype)
+    std = spec.scale / math.sqrt(_fan_in(spec))
+    return (jax.random.normal(key, spec.shape, dtype) * std).astype(dtype)
 
 
 def init_params(key, tree: Pytree, dtype=jnp.float32) -> Pytree:

@@ -18,7 +18,8 @@ neighbouring slots mid-decode ride along in the same batch with a one-token
 chunk — bit-exact with the token-by-token path by construction (the chunk
 kernel scans the same ``decode_step`` body over its columns).  Chunk widths
 are bucketed to powers of two so the jit cache holds at most
-``log2(prefill_chunk) + 1`` programs (``prefill_compiles`` counts them);
+``log2(prefill_chunk) + 1`` programs (``prefill_compiles`` counts them, and
+:meth:`ServeEngine.warmup` compiles them all before serving);
 ``prefill="token"`` keeps the old one-token-per-step ingestion as the
 measurable TTFT baseline.  Step accounting matches the virtual-time
 ``scheduler.simulate_serve``: every step charges the full batch width plus
@@ -35,6 +36,7 @@ flag or operating point disables the estimator and pins the point.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
@@ -154,7 +156,8 @@ class ServeEngine:
             estimator=estimator)
         self.requests: Dict[int, Request] = {}
         self.cache = init_cache(cfg, batch_slots, max_len, jnp.dtype(rc.dtype))
-        self._step = jax.jit(partial(decode_step, cfg=cfg, rc=rc))
+        #: the jitted decode step (one token per slot)
+        self.decode_fn = jax.jit(partial(decode_step, cfg=cfg, rc=rc))
         #: bucketed chunk-width jit cache: chunk width -> jitted prefill_step.
         #: Widths are powers of two, so at most log2(prefill_chunk)+1 programs
         #: ever compile; ``prefill_compiles`` counts them.
@@ -186,16 +189,17 @@ class ServeEngine:
         self.requests[rid] = Request(rid, list(prompt), max_new)
         return rid
 
-    def _reset_slot_cache(self, i: int) -> None:
-        """Zero slot ``i``'s rows in every cache leaf before reuse: batch is
+    @staticmethod
+    def _zero_slot(cache: Pytree, i: int) -> Pytree:
+        """``cache`` with slot ``i``'s rows zeroed in every leaf: batch is
         axis 1 of every stacked leaf, axis 0 of the per-sequence ``len``
         vector.  This is what makes mid-run refill safe — the readmitted
         slot restarts at position 0 over zeroed KV/state rows while its
         neighbours keep decoding at their own positions."""
-        self.cache = {k: (v if v.ndim == 0 else
-                          v.at[i].set(0) if v.ndim == 1 else
-                          v.at[:, i].set(0))
-                      for k, v in self.cache.items()}
+        return {k: (v if v.ndim == 0 else
+                    v.at[i].set(0) if v.ndim == 1 else
+                    v.at[:, i].set(0))
+                for k, v in cache.items()}
 
     # -- chunked prefill machinery ----------------------------------------
     @staticmethod
@@ -204,7 +208,9 @@ class ServeEngine:
         the number of compiled prefill programs stays logarithmic."""
         return 1 << max(n - 1, 0).bit_length()
 
-    def _prefill_fn(self, width: int):
+    def prefill_fn(self, width: int):
+        """The jitted :func:`~repro.models.model.prefill_step` for chunk
+        width ``width`` (compiled on its first call)."""
         fn = self._prefill_jit.get(width)
         if fn is None:
             fn = self._prefill_jit[width] = jax.jit(
@@ -240,7 +246,7 @@ class ServeEngine:
                 tokens[i, 0] = (req.generated[-1] if req.generated
                                 else req.prompt[-1])
                 counts[i] = 1
-        logits, self.cache = self._prefill_fn(width)(
+        logits, self.cache = self.prefill_fn(width)(
             self.params, self.cache,
             {"tokens": jnp.asarray(tokens), "n_tokens": jnp.asarray(counts)})
         return np.asarray(jnp.argmax(logits, axis=-1)), counts, prefill_tokens
@@ -261,9 +267,37 @@ class ServeEngine:
             else:
                 tokens[i, 0] = req.prompt[-1]
             counts[i] = 1
-        logits, self.cache = self._step(self.params, self.cache,
-                                        {"tokens": jnp.asarray(tokens)})
+        logits, self.cache = self.decode_fn(self.params, self.cache,
+                                            {"tokens": jnp.asarray(tokens)})
         return np.asarray(jnp.argmax(logits, axis=-1)), counts, 0
+
+    def warmup(self) -> Dict[str, float]:
+        """Compile every program :meth:`step` can dispatch — the decode
+        step, with chunked prefill each chunk-width bucket, and the slot
+        reset and argmax around them — before any request is served, so no
+        compile lands inside a served step.  Returns each step program's
+        first-call seconds, compile included.  The cache is left as it
+        was: every output is dropped."""
+        n = self.sched.n_slots
+        calls = [("decode_step", self.decode_fn,
+                  {"tokens": jnp.zeros((n, 1), jnp.int32)})]
+        if self.prefill == "chunked":
+            widths = {self._bucket(k) for k in range(1, self.prefill_chunk + 1)}
+            calls += [(f"prefill_step[{w}]", self.prefill_fn(w),
+                       {"tokens": jnp.zeros((n, w), jnp.int32),
+                        "n_tokens": jnp.zeros((n,), jnp.int32)})
+                      for w in sorted(widths)]
+        seconds = {}
+        for name, fn, batch in calls:
+            t0 = time.perf_counter()
+            # keep only the logits: a second live cache would raise the
+            # device's peak memory above what serving needs
+            logits = jax.block_until_ready(
+                fn(self.params, self.cache, batch)[0])
+            seconds[name] = time.perf_counter() - t0
+        np.asarray(jnp.argmax(logits, axis=-1))
+        jax.block_until_ready(self._zero_slot(self.cache, 0))
+        return seconds
 
     # -- measured-traffic retargeting --------------------------------------
     def _maybe_retarget_traffic(self) -> None:
@@ -299,7 +333,7 @@ class ServeEngine:
         slots from the arrival queue first (continuous batching)."""
         placed = self.sched.refill(self._clock)
         for i, _ in placed:
-            self._reset_slot_cache(i)
+            self.cache = self._zero_slot(self.cache, i)
         if placed:
             self._maybe_retarget_traffic()
         active = self.sched.active()

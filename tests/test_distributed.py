@@ -12,16 +12,7 @@ from repro.configs import get_config, get_reduced
 from repro.distributed.sharding import _leaf_pspec, param_pspecs
 from repro.roofline import Roofline, collective_bytes
 
-def _abstract_mesh(shape, names):
-    """AbstractMesh across JAX API generations: >=0.5 takes (shape, names);
-    0.4.x takes one ((name, size), ...) tuple."""
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 RC = RunConfig()
 RC_FSDP = RunConfig(fsdp=True)
 

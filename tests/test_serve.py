@@ -420,3 +420,62 @@ def test_engine_measured_traffic_retargets_at_refill():
     # the operating point only steers accounting, never the tokens
     for a, b in zip(rids, rids_p):
         assert done[a].generated == done_p[b].generated
+
+
+def test_engine_warmup_compiles_every_program_up_front():
+    """After warmup no served step compiles, and the warm-up calls leave the
+    cache as it was: the tokens match an engine that never warmed up."""
+    cfg = _cfg()
+    params = init_model_params(KEY, cfg)
+    prompts = [list(range(1, 12)), [5, 9], [3, 1, 4, 1, 5, 9]]
+
+    def serve(warm):
+        eng = ServeEngine(params, cfg, RC, batch_slots=2, max_len=64,
+                          prefill_chunk=4)
+        seconds = eng.warmup() if warm else {}
+        compiles = eng.prefill_compiles
+        rids = [eng.submit(p, max_new=4) for p in prompts]
+        done = eng.run()
+        return seconds, eng.prefill_compiles - compiles, \
+            [done[r].generated for r in rids]
+
+    seconds, new_compiles, tokens = serve(warm=True)
+    assert set(seconds) == {"decode_step", "prefill_step[1]",
+                            "prefill_step[2]", "prefill_step[4]"}
+    assert new_compiles == 0
+    assert tokens == serve(warm=False)[2]
+
+
+# --- chip_smoke.py's logits check ---------------------------------------------
+
+def _chip_smoke():
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("decode_offset", [0, 1])
+def test_chip_smoke_logits_check(decode_offset):
+    """The check chip_smoke.py runs on the chip, at the reduced preset: the
+    bf16 engine's teacher-forced logits agree with the float32 forward
+    within its tolerance, and a decode cache position off by one fails it
+    while the prefill positions still pass."""
+    cs = _chip_smoke()
+    from repro.launch.serve import build_engine
+    cfg = _cfg()
+    eng = build_engine(cfg, 0, batch_slots=4, max_len=64, prefill_chunk=8)
+    prompt = [int(t) for t in jax.random.randint(KEY, (20,), 0, cfg.vocab)]
+    rid = eng.submit(prompt, max_new=6)
+    generated = eng.run()[rid].generated
+    if decode_offset:
+        decode = eng.decode_fn
+        eng.decode_fn = lambda p, c, b: decode(
+            p, {**c, "len": c["len"] + decode_offset}, b)
+    err = cs.logits_error(eng, prompt, generated)
+    assert err.shape == (len(prompt) + len(generated) - 1,)
+    assert err[:len(prompt)].max() <= cs.LOGITS_TOL
+    assert (err.max() <= cs.LOGITS_TOL) == (decode_offset == 0)

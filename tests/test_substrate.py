@@ -5,6 +5,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.checkpoint import CheckpointManager, restore, save
@@ -263,3 +264,22 @@ def test_serve_engine_second_wave_matches_fresh_engine():
     fresh = ServeEngine(params, cfg, RC, batch_slots=2, max_len=64)
     rid_f = fresh.submit(prompt, max_new=max_new)
     assert second_wave == fresh.run()[rid_f].generated
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The entry points keep compiled programs in <repo>/.jax_cache unless
+    JAX_COMPILATION_CACHE_DIR names a directory, which JAX reads itself."""
+    from repro.launch import compile_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *kv: updates.append(kv))
+    compile_cache.enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = ([] if env_dir else
+            [("jax_compilation_cache_dir", os.path.join(root, ".jax_cache"))])
+    assert updates == want
