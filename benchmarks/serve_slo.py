@@ -51,6 +51,7 @@ import time
 import numpy as np
 
 from repro.core.policy import OperatingPoint
+from repro.serve import telemetry
 from repro.serve.scheduler import (AdmissionControl, HostDispatch, ServeSLO,
                                    StepCostModel, TraceRequest,
                                    simulate_serve)
@@ -276,7 +277,10 @@ def smoke():
 # This section runs the *real* jitted engine (not the virtual-time
 # simulation): the chunked prefill path (`models.model.prefill_step` driven
 # by `ServeEngine(prefill="chunked")`) against the token-by-token reference
-# (`prefill="token"`) on the same params, prompt and pinned cost model.
+# (`prefill="token"`) on the same params and prompt.  The engine stamps on
+# the host clock, so the cycles-domain TTFT charges each step the engine
+# logged from submit to first token (`repro.serve.telemetry`) at the pinned
+# cost model, by the prompt tokens that step ingested.
 #
 # Gates:
 # * generated tokens AND final cache rows are bit-exact between the two
@@ -320,26 +324,41 @@ def _prefill_engines(cfg):
     prompt = [int(t) for t in rng.randint(0, mcfg.vocab,
                                           size=cfg["prompt_len"])]
     max_len = cfg["prompt_len"] + cfg["max_new"] + 8
-    cost = _cost_model()
 
     def mk(prefill):
         return ServeEngine(params, mcfg, rc,
                            batch_slots=cfg["batch_slots"], max_len=max_len,
                            operating_point=OperatingPoint(),
-                           cost_model=cost, prefill=prefill,
+                           prefill=prefill,
                            prefill_chunk=cfg["prefill_chunk"])
     return mk, prompt
 
 
+def _ttft_cycles(eng, prompt, max_new):
+    """Serve one request to its first token and charge each step the engine
+    logged from its submit on at the pinned cost model: a full-width
+    decode pass, plus the prompt tokens of a chunked prefill step (a
+    token-path step carries its prompt token in a decode column).  That is
+    the request's cycles-equivalent TTFT.  The request is then drained;
+    returns its rid and the TTFT."""
+    cost = _cost_model()
+    t_submit = time.perf_counter()
+    rid = eng.submit(prompt, max_new=max_new)
+    while not eng.requests[rid].generated:
+        eng.step()
+    cycles = sum(cost.step_cost(
+        eng.sched.n_slots,
+        rec.prompt_tokens if rec.kind == "prefill" else 0)[0]
+        for rec in telemetry.steps(t_submit) if rec.kind != "empty")
+    eng.run(max_steps=100_000)
+    return rid, cycles
+
+
 def _measure_ttft(eng, prompt, max_new, trials):
     """Warm run (compiles) + ``trials`` timed runs; returns the warm run's
-    generated tokens, the cycles-domain TTFT, and per-trial wall TTFTs."""
-    import math as _math
-    rid0 = eng.submit(prompt, max_new=max_new)
-    eng.run(max_steps=100_000)
+    generated tokens, its cycles-domain TTFT, and per-trial wall TTFTs."""
+    rid0, ttft_cycles = _ttft_cycles(eng, prompt, max_new)
     tokens = list(eng.finished[rid0].generated)
-    sreq = eng.sched.requests[rid0]
-    ttft_cycles = sreq.first_token - sreq.arrival
     walls = []
     for _ in range(trials):
         rid = eng.submit(prompt, max_new=max_new)
@@ -348,7 +367,6 @@ def _measure_ttft(eng, prompt, max_new, trials):
             eng.step()
         walls.append(time.time() - t0)
         eng.run(max_steps=100_000)           # drain before the next trial
-    assert _math.isfinite(ttft_cycles)
     return tokens, ttft_cycles, walls
 
 

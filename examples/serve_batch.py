@@ -1,13 +1,9 @@
 """Batched serving: continuous batching over a reduced model, several
-concurrent requests of different lengths, with chunked prefill and
-measured-traffic operating points.
+concurrent requests of different lengths, with chunked prefill.
 
-No ``traffic`` argument is passed to the engine, so it runs in
-measured-traffic mode: a TrafficEstimator watches the arrival stream and,
-once warm, re-resolves the calibrated per-traffic operating point at the
-next refill boundary.  The burst of same-clock submissions below saturates
-the estimate, so the engine retargets to the "high" traffic point mid-run
-— watch the traffic history it prints.
+The engine stamps every request on the host clock and logs each step's
+phases (``repro.serve.telemetry``); the example prints the TTFT and
+per-token latency percentiles and the mean time of each step phase.
 
   PYTHONPATH=src python examples/serve_batch.py --arch recurrentgemma-2b
 """
@@ -19,7 +15,7 @@ import jax
 from repro.config import RunConfig
 from repro.configs import ARCHS, get_reduced
 from repro.models import init_model_params
-from repro.serve import ServeEngine
+from repro.serve import ServeEngine, telemetry
 
 
 def main():
@@ -37,27 +33,27 @@ def main():
     params = init_model_params(jax.random.PRNGKey(0), cfg)
     eng = ServeEngine(params, cfg, rc, batch_slots=3, max_len=128,
                       prefill=args.prefill)
-    print(f"measured-traffic mode: level starts {eng.traffic_level} "
-          f"(estimator cold), prefill={args.prefill}")
+    eng.warmup()
 
-    # a same-clock burst: offered load saturates -> the estimator reads
-    # "high" and the engine retargets at the first refill boundary
+    # more requests than slots: later ones wait in the queue for a slot
+    t0 = time.perf_counter()
     for i in range(args.requests):
         prompt = list(range(1 + i, 5 + 2 * i))
         eng.submit(prompt, max_new=args.max_new)
 
-    t0 = time.time()
     done = eng.run()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     n_tok = sum(len(r.generated) for r in done.values())
     print(f"{cfg.name}: {len(done)} requests, {n_tok} tokens, "
           f"{n_tok/dt:.1f} tok/s, {eng._n_steps} engine steps "
-          f"({eng.prefill_compiles} prefill chunk programs)")
-    print(f"measured traffic level: {eng.traffic_level}; "
-          f"{len(eng.traffic_history)} retarget(s)")
-    for h in eng.traffic_history:
-        print(f"  @{h['clock']:.0f} cyc -> {h['level']} "
-              f"(rho~{h['offered_load']:.2f}, policy={h['policy']})")
+          f"({eng.prefill_compiles} prefill chunk programs), "
+          f"prefill={args.prefill}")
+    rep = eng.metrics()
+    print(f"host clock: TTFT p50/p99 {1e3 * rep.p50_ttft:.1f}/"
+          f"{1e3 * rep.p99_ttft:.1f} ms, latency p50/p99 "
+          f"{1e3 * rep.p50_latency:.2f}/{1e3 * rep.p99_latency:.2f} "
+          f"ms per output token")
+    print("step log: " + telemetry.summary(telemetry.steps(t0)))
     for rid in sorted(done):
         r = done[rid]
         print(f"  req{rid} prompt[:4]={r.prompt[:4]} -> {r.generated}")

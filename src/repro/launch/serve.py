@@ -13,7 +13,7 @@ from ..config import ModelConfig, RunConfig
 from ..configs import ARCHS, get_config, get_reduced
 from ..core.policy import TRAFFIC_LEVELS
 from ..models import init_model_params
-from ..serve import ServeEngine
+from ..serve import ServeEngine, telemetry
 from .compile_cache import enable_compile_cache
 
 
@@ -45,11 +45,9 @@ def main() -> None:
                          "batching, the measurable baseline)")
     ap.add_argument("--traffic", choices=sorted(TRAFFIC_LEVELS),
                     default=None,
-                    help="OVERRIDE the measured offered-load level: pins "
-                         "the calibration artifact's per-traffic serve-slo "
-                         "operating point (schema v5). Without it the "
-                         "engine estimates the level from the arrival "
-                         "stream and re-resolves at refill boundaries")
+                    help="pin the calibration artifact's per-traffic "
+                         "serve-slo operating point (schema v5) for this "
+                         "offered-load level")
     ap.add_argument("--prefill", choices=("chunked", "token"),
                     default="chunked",
                     help="prompt ingestion: chunked (jitted prefill_step, "
@@ -68,11 +66,9 @@ def main() -> None:
                        mode=args.mode, traffic=args.traffic,
                        prefill=args.prefill, prefill_chunk=args.prefill_chunk)
     op = eng.operating_point
-    traffic = (f"traffic={args.traffic} (pinned)" if args.traffic
-               else "traffic=measured")
     print(f"policy={op.policy.value} (source={op.source}, "
           f"cores={op.n_cores}, slots={len(eng.slots)}, mode={args.mode}, "
-          f"prefill={args.prefill}, {traffic})")
+          f"prefill={args.prefill}, traffic={args.traffic or 'default'})")
 
     rng = jax.random.PRNGKey(args.seed + 1)
     rids = []
@@ -83,26 +79,18 @@ def main() -> None:
                   jax.random.randint(k, (plen,), 0, cfg.vocab)]
         rids.append((eng.submit(prompt, max_new=args.max_new), prompt))
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     done = eng.run()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     total_tokens = sum(len(r.generated) for r in done.values())
     print(f"served {len(done)} requests, {total_tokens} tokens "
           f"in {dt:.1f}s ({total_tokens/dt:.1f} tok/s)")
     rep = eng.metrics()
-    print(f"calibrated accounting ({rep.cost_source}): "
-          f"{rep.throughput:.5f} tok/cycle, "
-          f"{rep.energy_per_token:.1f} J-equiv/token, "
-          f"p50/p99 latency {rep.p50_latency:.1f}/{rep.p99_latency:.1f} "
-          f"cyc/tok, p50 TTFT {rep.p50_ttft:.0f} cyc")
-    if args.traffic is None:
-        level = eng.traffic_level or "still cold (too few arrivals)"
-        print(f"measured traffic: {level}; "
-              f"{len(eng.traffic_history)} retarget(s)")
-        for h in eng.traffic_history:
-            print(f"  @{h['clock']:.0f} cyc -> {h['level']} "
-                  f"(rho~{h['offered_load']:.2f}, policy={h['policy']}, "
-                  f"source={h['source']})")
+    print(f"host clock: TTFT p50/p99 {1e3 * rep.p50_ttft:.1f}/"
+          f"{1e3 * rep.p99_ttft:.1f} ms, latency p50/p99 "
+          f"{1e3 * rep.p50_latency:.2f}/{1e3 * rep.p99_latency:.2f} "
+          f"ms per output token")
+    print("step log: " + telemetry.summary(telemetry.steps(t0)))
     for rid, prompt in rids:
         r = done[rid]
         print(f"  req{rid}: prompt={prompt} -> {r.generated}")

@@ -305,11 +305,14 @@ def decode_step(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
             bp, lat, rp = sl
             bp = cast(bp)
             hn = rms_norm(h, bp["ln1"], cfg.norm_eps)
-            y, lat, rp = attn.mla_decode(bp["attn"], hn, cfg, lat, rp, length)
+            with jax.named_scope("attention"):
+                y, lat, rp = attn.mla_decode(bp["attn"], hn, cfg, lat, rp,
+                                             length)
             h = h + y
             hn = rms_norm(h, bp["ln2"], cfg.norm_eps)
-            y = (moe_mod.moe_apply(bp["ffn"], hn, cfg) if cfg.moe
-                 else ffn_apply(bp["ffn"], hn, cfg.ffn_act))
+            with jax.named_scope("ffn"):
+                y = (moe_mod.moe_apply(bp["ffn"], hn, cfg) if cfg.moe
+                     else ffn_apply(bp["ffn"], hn, cfg.ffn_act))
             return h + y, (lat, rp)
         x, (lat, rp) = _stack_scan(
             body, x, (params["blocks"], cache["latent"], cache["rope"]), rc)
@@ -319,11 +322,14 @@ def decode_step(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
             bp, kc, vc = sl
             bp = cast(bp)
             hn = rms_norm(h, bp["ln1"], cfg.norm_eps)
-            y, kc, vc = attn.gqa_decode(bp["attn"], hn, cfg, kc, vc, length)
+            with jax.named_scope("attention"):
+                y, kc, vc = attn.gqa_decode(bp["attn"], hn, cfg, kc, vc,
+                                            length)
             h = h + y
             hn = rms_norm(h, bp["ln2"], cfg.norm_eps)
-            y = (moe_mod.moe_apply(bp["ffn"], hn, cfg) if cfg.moe
-                 else ffn_apply(bp["ffn"], hn, cfg.ffn_act))
+            with jax.named_scope("ffn"):
+                y = (moe_mod.moe_apply(bp["ffn"], hn, cfg) if cfg.moe
+                     else ffn_apply(bp["ffn"], hn, cfg.ffn_act))
             return h + y, (kc, vc)
         x, (kc, vc) = _stack_scan(
             body, x, (params["blocks"], cache["k"], cache["v"]), rc)
@@ -331,7 +337,8 @@ def decode_step(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
-    logits = jnp.einsum("bsd,vd->bsv", x, head.astype(dtype))[:, 0]
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x, head.astype(dtype))[:, 0]
     return logits.astype(jnp.float32), cache
 
 
@@ -464,8 +471,9 @@ def prefill_step(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
         active = j < n_tokens                                      # (B,)
         step_logits, new_cache = decode_step(params, cache,
                                              {"tokens": tok}, cfg, rc)
-        cache = {k: _merge_masked(active, new_cache[k], cache[k])
-                 for k in cache}
+        with jax.named_scope("cache_merge"):
+            cache = {k: _merge_masked(active, new_cache[k], cache[k])
+                     for k in cache}
         logits = jnp.where(active[:, None], step_logits, logits)
         return (cache, logits), None
 

@@ -21,37 +21,32 @@ are bucketed to powers of two so the jit cache holds at most
 ``log2(prefill_chunk) + 1`` programs (``prefill_compiles`` counts them, and
 :meth:`ServeEngine.warmup` compiles them all before serving);
 ``prefill="token"`` keeps the old one-token-per-step ingestion as the
-measurable TTFT baseline.  Step accounting matches the virtual-time
-``scheduler.simulate_serve``: every step charges the full batch width plus
-the ingested prompt tokens at ``PREFILL_FRACTION`` through the
-:class:`StepCostModel`, so the engine clock is in cycles-equivalent always.
+measurable TTFT baseline.
 
-When neither an explicit ``operating_point`` nor a ``traffic`` level is
-given, the engine runs in *measured-traffic* mode: a
-:class:`~repro.serve.scheduler.TrafficEstimator` watches the arrival
-stream, and at refill boundaries the engine re-resolves the schema-v5
-per-traffic ``serve-slo`` operating point for the measured level
-(``traffic_history`` records every retarget).  An explicit ``traffic``
-flag or operating point disables the estimator and pins the point.
+The engine runs on the host clock: every request lifecycle stamp is
+``time.perf_counter()`` seconds.  Each phase of :meth:`ServeEngine.step` is
+a profiler span, and each step appends one record to the process-wide step
+log (:mod:`repro.serve.telemetry`).  The step programs are jitted under
+their own names, so a device trace shows ``jit_decode_step`` and
+``jit_prefill_step``.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from ..config import (ModelConfig, RunConfig, _DEFAULT_RC_POLICY,
-                      resolve_run_config)
-from ..core.policy import OperatingPoint, PolicyTable, default_table
+from ..config import ModelConfig, RunConfig, resolve_run_config
+from ..core.policy import OperatingPoint, PolicyTable
 from ..models.model import decode_step, init_cache, prefill_step
-from .scheduler import (AdmissionControl, ContinuousScheduler, HostDispatch,
-                        ServeReport, ServeSLO, StepCostModel,
-                        TrafficEstimator, build_report)
+from . import telemetry
+from .scheduler import (AdmissionControl, ContinuousScheduler, ServeReport,
+                        ServeSLO, build_report)
 
 Pytree = Any
 
@@ -63,6 +58,16 @@ class Request:
     max_new: int
     generated: List[int] = field(default_factory=list)
     done: bool = False
+
+
+def _jit_step(fn, cfg: ModelConfig, rc: RunConfig):
+    """``fn(params, cache, batch, cfg=cfg, rc=rc)`` jitted under ``fn``'s
+    own name, so that its program is ``jit_<name>`` in a device trace (a
+    ``functools.partial`` would be ``jit__unknown``)."""
+    def step(params, cache, batch):
+        return fn(params, cache, batch, cfg=cfg, rc=rc)
+    step.__name__ = step.__qualname__ = fn.__name__
+    return jax.jit(step)
 
 
 class ServeEngine:
@@ -77,13 +82,9 @@ class ServeEngine:
     ``"serve"`` workload's point, falling back to the paper's defaults when
     no artifact exists.  A ``traffic`` level ("low"/"medium"/"high") pins
     the artifact's per-traffic ``serve-slo`` point when the calibration
-    carries one (schema v5); with no pin the engine *measures* the level
-    from the arrival stream and re-resolves at refill boundaries (the
-    retarget swaps the operating point and its cost model — the executed
-    numeric program is untouched, so generated tokens never depend on
-    traffic).  The resolved policy is threaded into the engine's
-    :class:`RunConfig` so every kernel the decode path reaches sees it; the
-    resolution itself never touches the per-step hot path.
+    carries one (schema v5).  The resolved policy is threaded into the
+    engine's :class:`RunConfig` so every kernel the decode path reaches
+    sees it; the resolution itself never touches the per-step hot path.
 
     Batch sizing is cluster-aware: with ``batch_slots=None`` the engine
     sizes its decode batch as ``SLOTS_PER_CORE * n_cores`` from the
@@ -93,10 +94,11 @@ class ServeEngine:
     explicit ``batch_slots`` always wins.
 
     Request lifecycle and accounting live in
-    :class:`~repro.serve.scheduler.ContinuousScheduler`; :meth:`metrics`
-    turns the recorded timestamps (cycles-equivalent — the engine clock is
-    driven by the operating point's :class:`StepCostModel`) into p50/p99
-    latency and energy-per-token.
+    :class:`~repro.serve.scheduler.ContinuousScheduler`, stamped with
+    ``time.perf_counter()``: a request arrives at :meth:`submit`, enters a
+    slot at the start of the step that places it, and gets each token at
+    the end of the step's ``serve.fetch`` phase.  :meth:`metrics` turns the
+    stamps into p50/p99 latency and TTFT in seconds.
     """
 
     #: decode slots the batch allocates per cluster core (one PE's worth of
@@ -112,8 +114,6 @@ class ServeEngine:
                  policy_table: Optional[PolicyTable] = None,
                  mode: str = "continuous", max_pending: int = 64,
                  traffic: Optional[str] = None,
-                 cost_model: Optional[StepCostModel] = None,
-                 dispatch: Optional[HostDispatch] = None,
                  prefill: str = "chunked", prefill_chunk: int = 8):
         assert cfg.causal, "serving requires an autoregressive model"
         if prefill not in self.PREFILL_MODES:
@@ -121,7 +121,6 @@ class ServeEngine:
                              f"got {prefill!r}")
         assert prefill_chunk >= 1, prefill_chunk
         self.params = params
-        pinned = rc.policy if rc.policy is not _DEFAULT_RC_POLICY else None
         rc, self.operating_point = resolve_run_config(
             rc, "serve", operating_point, policy_table, traffic=traffic)
         if batch_slots is None:
@@ -133,31 +132,14 @@ class ServeEngine:
         self.greedy = greedy
         self.prefill = prefill
         self.prefill_chunk = prefill_chunk
-        self._cost = cost_model or StepCostModel.from_operating_point(
-            self.operating_point)
-        self._explicit_cost = cost_model is not None
-        # measured-traffic mode: no pinned point, no pinned level — estimate
-        # offered load from arrivals and re-resolve at refill boundaries
-        self._measured = operating_point is None and traffic is None
-        self._pinned_policy = pinned
-        self._table = (policy_table if policy_table is not None
-                       else default_table())
-        self.traffic_level: Optional[str] = traffic
-        self.traffic_history: List[Dict[str, Any]] = []
-        estimator = None
-        if self._measured:
-            step_cyc, _ = self._cost.step_cost(batch_slots, 0)
-            estimator = TrafficEstimator(
-                capacity_tokens_per_cycle=batch_slots / max(step_cyc, 1e-9))
         self.sched = ContinuousScheduler(
             batch_slots, mode=mode,
             admission=AdmissionControl(max_pending=max_pending,
-                                       max_total_len=max_len),
-            estimator=estimator)
+                                       max_total_len=max_len))
         self.requests: Dict[int, Request] = {}
         self.cache = init_cache(cfg, batch_slots, max_len, jnp.dtype(rc.dtype))
         #: the jitted decode step (one token per slot)
-        self.decode_fn = jax.jit(partial(decode_step, cfg=cfg, rc=rc))
+        self.decode_fn = _jit_step(decode_step, cfg, rc)
         #: bucketed chunk-width jit cache: chunk width -> jitted prefill_step.
         #: Widths are powers of two, so at most log2(prefill_chunk)+1 programs
         #: ever compile; ``prefill_compiles`` counts them.
@@ -165,10 +147,7 @@ class ServeEngine:
         self.prefill_compiles = 0
         self._next_rid = 0
         self.finished: Dict[int, Request] = {}
-        self._dispatch = dispatch
         self._n_steps = 0
-        self._clock = 0.0       # cycles-equivalent (StepCostModel-driven)
-        self._energy = 0.0
 
     @property
     def slots(self) -> List[Optional[Request]]:
@@ -184,7 +163,8 @@ class ServeEngine:
         scheduler's admission control refuses empty prompts up front, so a
         ``[]`` prompt never reaches the batch-assembly hot path."""
         rid = self._next_rid
-        self.sched.submit(rid, len(prompt), max_new, now=self._clock)
+        self.sched.submit(rid, len(prompt), max_new,
+                          now=time.perf_counter())
         self._next_rid += 1
         self.requests[rid] = Request(rid, list(prompt), max_new)
         return rid
@@ -213,27 +193,25 @@ class ServeEngine:
         width ``width`` (compiled on its first call)."""
         fn = self._prefill_jit.get(width)
         if fn is None:
-            fn = self._prefill_jit[width] = jax.jit(
-                partial(prefill_step, cfg=self.cfg, rc=self.rc))
+            fn = self._prefill_jit[width] = _jit_step(prefill_step,
+                                                      self.cfg, self.rc)
             self.prefill_compiles += 1
         return fn
 
-    def _chunk_forward(self, active) -> Tuple[np.ndarray, np.ndarray, int]:
-        """One mixed-phase chunk call: prefilling slots ingest up to
+    def _chunk_batch(self, active):
+        """A mixed-phase chunk batch: prefilling slots ingest up to
         ``prefill_chunk`` prompt tokens, decoding slots ride along with a
-        one-token chunk, free slots stay masked out.  Returns the per-slot
-        argmax tokens, the per-slot chunk counts, and the total prompt
-        tokens ingested (the prefill component of this step's cost)."""
+        one-token chunk, free slots stay masked out.  Returns the token and
+        per-slot count arrays and the prompt tokens they ingest."""
         n = self.sched.n_slots
         need = 1
         for _, sreq in active:
             if sreq.phase == "prefill":
                 need = max(need, min(self.prefill_chunk,
                                      sreq.prompt_len - sreq.prefill_cursor))
-        width = self._bucket(need)
-        tokens = np.zeros((n, width), np.int32)
+        tokens = np.zeros((n, self._bucket(need)), np.int32)
         counts = np.zeros((n,), np.int32)
-        prefill_tokens = 0
+        prompt_tokens = 0
         for i, sreq in active:
             req = self.requests[sreq.rid]
             cur = sreq.prefill_cursor
@@ -241,35 +219,32 @@ class ServeEngine:
                 k = min(self.prefill_chunk, len(req.prompt) - cur)
                 tokens[i, :k] = req.prompt[cur:cur + k]
                 counts[i] = k
-                prefill_tokens += k
+                prompt_tokens += k
             else:
                 tokens[i, 0] = (req.generated[-1] if req.generated
                                 else req.prompt[-1])
                 counts[i] = 1
-        logits, self.cache = self.prefill_fn(width)(
-            self.params, self.cache,
-            {"tokens": jnp.asarray(tokens), "n_tokens": jnp.asarray(counts)})
-        return np.asarray(jnp.argmax(logits, axis=-1)), counts, prefill_tokens
+        return tokens, counts, prompt_tokens
 
-    def _token_forward(self, active) -> Tuple[np.ndarray, np.ndarray, int]:
-        """One token-by-token step (pure-decode steps, and the whole run
-        when ``prefill="token"``): every active slot advances one token
-        through the plain jitted decode step."""
+    def _token_batch(self, active):
+        """A one-token batch (pure-decode steps, and the whole run when
+        ``prefill="token"``): every active slot advances one token through
+        the plain decode step."""
         tokens = np.zeros((self.sched.n_slots, 1), np.int32)
         counts = np.zeros((self.sched.n_slots,), np.int32)
+        prompt_tokens = 0
         for i, sreq in active:
             req = self.requests[sreq.rid]
             cur = sreq.prefill_cursor
             if cur < len(req.prompt):
                 tokens[i, 0] = req.prompt[cur]
+                prompt_tokens += 1
             elif req.generated:
                 tokens[i, 0] = req.generated[-1]
             else:
                 tokens[i, 0] = req.prompt[-1]
             counts[i] = 1
-        logits, self.cache = self.decode_fn(self.params, self.cache,
-                                            {"tokens": jnp.asarray(tokens)})
-        return np.asarray(jnp.argmax(logits, axis=-1)), counts, 0
+        return tokens, counts, prompt_tokens
 
     def warmup(self) -> Dict[str, float]:
         """Compile every program :meth:`step` can dispatch — the decode
@@ -299,73 +274,67 @@ class ServeEngine:
         jax.block_until_ready(self._zero_slot(self.cache, 0))
         return seconds
 
-    # -- measured-traffic retargeting --------------------------------------
-    def _maybe_retarget_traffic(self) -> None:
-        """In measured-traffic mode, re-resolve the per-traffic operating
-        point when the estimator's level moved.  Called at refill
-        boundaries only — never on the per-token hot path — and only swaps
-        the accounting (operating point, cost model, estimator capacity):
-        the compiled decode/prefill programs are left alone, so retargeting
-        can never change which tokens get generated."""
-        est = self.sched.estimator
-        if not self._measured or est is None:
-            return
-        level = est.level()
-        if level is None or level == self.traffic_level:
-            return
-        kw = ({"policy": self._pinned_policy}
-              if self._pinned_policy is not None else {})
-        op = self._table.resolve("serve", traffic=level, **kw)
-        self.traffic_level = level
-        self.operating_point = op
-        if not self._explicit_cost:
-            self._cost = StepCostModel.from_operating_point(op)
-        step_cyc, _ = self._cost.step_cost(self.sched.n_slots, 0)
-        est.capacity = self.sched.n_slots / max(step_cyc, 1e-9)
-        self.traffic_history.append({
-            "clock": self._clock, "level": level,
-            "offered_load": est.offered_load(),
-            "policy": op.policy.value, "source": op.source})
-
     def step(self) -> None:
         """Advance every active slot — one chunk of prompt tokens for
         prefilling slots, one decoded token for the rest — refilling freed
-        slots from the arrival queue first (continuous batching)."""
-        placed = self.sched.refill(self._clock)
-        for i, _ in placed:
-            self.cache = self._zero_slot(self.cache, i)
-        if placed:
-            self._maybe_retarget_traffic()
-        active = self.sched.active()
+        slots from the arrival queue first (continuous batching).  Each
+        phase is a profiler span, and the call appends one
+        :class:`~repro.serve.telemetry.StepRecord` to the step log."""
+        t0 = time.perf_counter()
+        with TraceAnnotation(telemetry.REFILL):
+            placed = self.sched.refill(t0)
+            for i, _ in placed:
+                self.cache = self._zero_slot(self.cache, i)
+            active = self.sched.active()
+        t_refill = time.perf_counter()
         if not active:
+            telemetry.LOG.append(telemetry.StepRecord(
+                t0, t_refill, t_refill, t_refill, t_refill, t_refill,
+                t_refill, "empty", 0, 0, 0, len(placed)))
             return
-        if self.prefill == "chunked" and any(
-                sreq.phase == "prefill" for _, sreq in active):
-            nxt, counts, prefill_tokens = self._chunk_forward(active)
-        else:
-            nxt, counts, prefill_tokens = self._token_forward(active)
-        cycles, joules = self._cost.step_cost(self.sched.n_slots,
-                                              prefill_tokens)
-        if self._dispatch is not None:
-            cycles = self._dispatch.step(cycles, self._clock)
-        end = self._clock + cycles
-        self._energy += joules
-        for i, sreq in active:
-            req = self.requests[sreq.rid]
-            cur = sreq.prefill_cursor
-            if cur < len(req.prompt):
-                k = int(counts[i])
-                self.sched.advance_prefill(sreq.rid, k, end)
-                if cur + k < len(req.prompt):
-                    continue               # still ingesting the prompt
-                # the call that ingested the last prompt token emitted the
-                # first generated token — fall through to record it
-            req.generated.append(int(nxt[i]))
-            if self.sched.record_token(sreq.rid, end):
-                req.done = True
-                self.finished[req.rid] = req
-        self._clock = end
-        self._n_steps += 1
+        chunked = self.prefill == "chunked" and any(
+            sreq.phase == "prefill" for _, sreq in active)
+        with TraceAnnotation(telemetry.ASSEMBLE):
+            if chunked:
+                tokens, counts, prompt_tokens = self._chunk_batch(active)
+                fn = self.prefill_fn(tokens.shape[1])
+                batch = {"tokens": jnp.asarray(tokens),
+                         "n_tokens": jnp.asarray(counts)}
+            else:
+                tokens, counts, prompt_tokens = self._token_batch(active)
+                fn = self.decode_fn
+                batch = {"tokens": jnp.asarray(tokens)}
+        t_assemble = time.perf_counter()
+        with TraceAnnotation(telemetry.DISPATCH):
+            logits, self.cache = fn(self.params, self.cache, batch)
+            nxt = jnp.argmax(logits, axis=-1)
+        t_dispatch = time.perf_counter()
+        with TraceAnnotation(telemetry.WAIT):
+            jax.block_until_ready(nxt)
+        t_wait = time.perf_counter()
+        with TraceAnnotation(telemetry.FETCH):
+            nxt = np.asarray(nxt)
+        end = time.perf_counter()           # the step's tokens' stamp
+        with TraceAnnotation(telemetry.COMMIT):
+            for i, sreq in active:
+                req = self.requests[sreq.rid]
+                cur = sreq.prefill_cursor
+                if cur < len(req.prompt):
+                    k = int(counts[i])
+                    self.sched.advance_prefill(sreq.rid, k, end)
+                    if cur + k < len(req.prompt):
+                        continue               # still ingesting the prompt
+                    # the call that ingested the last prompt token emitted
+                    # the first generated token — fall through to record it
+                req.generated.append(int(nxt[i]))
+                if self.sched.record_token(sreq.rid, end):
+                    req.done = True
+                    self.finished[req.rid] = req
+            self._n_steps += 1
+        telemetry.LOG.append(telemetry.StepRecord(
+            t0, t_refill, t_assemble, t_dispatch, t_wait, end,
+            time.perf_counter(), "prefill" if chunked else "decode",
+            tokens.shape[1], prompt_tokens, len(active), len(placed)))
 
     def run(self, max_steps: int = 1000) -> Dict[int, Request]:
         steps = 0
@@ -375,12 +344,14 @@ class ServeEngine:
         return self.finished
 
     def metrics(self, slo: Optional[ServeSLO] = None) -> ServeReport:
-        """Per-request serving report (p50/p99 latency, TTFT, J/token,
-        SLO attainment).  Timestamps are already in cycles-equivalent —
-        every step is charged through the operating point's
-        :class:`StepCostModel` as it executes (full batch width plus the
-        step's prompt tokens at ``PREFILL_FRACTION``), the same accounting
-        ``simulate_serve`` applies in virtual time."""
-        return build_report(self.sched, self._clock, self._energy,
-                            slo=slo, dispatch=self._dispatch,
-                            cost_source=self._cost.source)
+        """Per-request serving report (p50/p99 latency, TTFT, SLO
+        attainment) from the scheduler's lifecycle stamps, which are
+        ``time.perf_counter()`` seconds: latencies are seconds per output
+        token, TTFTs seconds, throughput tokens per second from the first
+        arrival to the last finish.  The engine measures no energy, so the
+        energy fields read 0."""
+        stamps = [t for r in self.sched.requests.values()
+                  for t in (r.arrival, r.finish) if t is not None]
+        span = max(stamps) - min(stamps) if stamps else 0.0
+        return build_report(self.sched, span, 0.0, slo=slo,
+                            cost_source="host_clock")

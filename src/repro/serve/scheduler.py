@@ -3,12 +3,13 @@ SLO accounting on top of the calibrated machine model.
 
 This module is the bookkeeping half of the serving stack: `engine.ServeEngine`
 owns the jitted decode step and the KV cache, while the scheduler owns the
-arrival queue, admission control, slot assignment, per-request timestamps and
-the cost model that converts engine steps into cycles-equivalent latency and
-joules-per-token.  `simulate_serve` drives the same scheduler in virtual time
-(no model, just the calibrated step costs) — that is what the trace-driven
-`benchmarks/serve_slo.py` load generator runs, so the benchmark's batching
-comparison and the real engine share one lifecycle implementation.
+arrival queue, admission control, slot assignment and per-request timestamps.
+The live engine stamps them with the host clock (``time.perf_counter()``
+seconds).  `simulate_serve` drives the same scheduler in virtual time (no
+model, just the cost model's cycles-equivalent step costs) — that is what the
+trace-driven `benchmarks/serve_slo.py` load generator runs, so the
+benchmark's batching comparison and the real engine share one lifecycle
+implementation.
 
 SLO objective semantics
 -----------------------
@@ -96,8 +97,8 @@ class ServeRequest:
     """Scheduler-side view of one request: shape plus lifecycle timestamps.
 
     Times are in whatever unit the caller's clock uses — cycles-equivalent in
-    the virtual-time simulation, engine steps in the live engine (converted
-    to cycles by the :class:`StepCostModel` when reporting).
+    the virtual-time simulation, ``time.perf_counter()`` seconds in the live
+    engine.
     """
     rid: int
     prompt_len: int
@@ -475,7 +476,10 @@ class ServeSLO:
 @dataclass
 class ServeReport:
     """Per-run serving metrics: request outcomes, latency percentiles,
-    energy accounting and SLO attainment."""
+    energy accounting and SLO attainment.  Times are in the clock unit of
+    the stamps, named for the virtual-time simulation's cycles: seconds
+    for the live engine (``cost_source="host_clock"``), which measures no
+    energy."""
     mode: str
     n_completed: int
     n_rejected: int
@@ -483,11 +487,11 @@ class ServeReport:
     total_cycles: float
     total_energy: float
     tokens_out: int
-    throughput: float                 # tokens / cycle, all completions
+    throughput: float                 # tokens / time unit, all completions
     energy_per_token: float           # joules / token, all tokens
-    p50_latency: float                # normalized: cycles per work-token
+    p50_latency: float                # normalized: time per work-token
     p99_latency: float
-    p50_ttft: float                   # time to first token, cycles
+    p50_ttft: float                   # time to first token
     p99_ttft: float
     slo: Optional[Dict[str, Any]] = None
     straggler: Optional[Dict[str, Any]] = None

@@ -2,8 +2,10 @@
 slot refill, straggler-aware host dispatch, SLO accounting on the
 virtual-time simulation, the live engine's continuous-batching equivalence
 (a mid-run admitted request decodes the same tokens as on a fresh engine),
-chunked-prefill bit-exactness on mixed-phase batches, and measured-traffic
-operating-point retargeting."""
+chunked-prefill bit-exactness on mixed-phase batches, pinned-traffic
+operating points, and the engine's host-clock stamps and step log."""
+import time
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -235,14 +237,18 @@ def test_engine_admission_error_and_metrics():
     eng = ServeEngine(params, cfg, RC, batch_slots=2, max_len=16)
     with pytest.raises(AdmissionError, match="cache rows"):
         eng.submit(list(range(14)), max_new=8)
+    t_before = time.perf_counter()
     eng.submit([1, 2, 3], max_new=4)
     eng.run()
+    t_after = time.perf_counter()
     rep = eng.metrics(slo=ServeSLO(p99_cycles_per_token=1e9))
     assert rep.mode == "continuous"
     assert rep.n_completed == 1 and rep.n_rejected == 1
     assert rep.tokens_out == 4
     assert rep.slo["attainment"] == 1.0
-    assert rep.cost_source in ("calibrated", "default", "flat-fallback")
+    assert rep.cost_source == "host_clock" and rep.total_energy == 0.0
+    # TTFTs are host seconds inside the test's own clock readings
+    assert 0.0 < rep.p50_ttft <= rep.p99_ttft <= t_after - t_before
 
 
 def test_engine_static_mode_still_serves_everything():
@@ -396,28 +402,24 @@ def test_scheduler_estimator_observes_shed_arrivals_too():
 
 
 def test_engine_measured_traffic_retargets_at_refill():
-    """With neither an operating point nor a --traffic pin, the engine
-    estimates the level from arrivals and re-resolves the operating point
-    at a refill boundary; retargeting never changes generated tokens."""
+    """The engine no longer measures traffic, so no refill retargets it: a
+    pinned ``traffic`` level resolves the operating point at startup,
+    attaches no traffic estimator and holds across every refill; the
+    operating point never changes the generated tokens."""
     cfg = _cfg()
     params = init_model_params(KEY, cfg)
     eng = ServeEngine(params, cfg, RC, batch_slots=2, max_len=64)
-    assert eng.sched.estimator is not None and eng.traffic_level is None
     rids = [eng.submit([1 + i, 2, 3], max_new=2) for i in range(5)]
     done = eng.run()
     assert set(done) == set(rids)
-    # 5 same-clock arrivals saturate the estimator -> "high" at first refill
-    assert eng.traffic_level == "high"
-    assert eng.traffic_history and \
-        eng.traffic_history[0]["level"] == "high"
 
     pinned = ServeEngine(params, cfg, RC, batch_slots=2, max_len=64,
                          traffic="medium")
-    assert pinned.sched.estimator is None        # static override
-    assert pinned.traffic_level == "medium" and not pinned.traffic_history
+    assert pinned.sched.estimator is None and pinned.traffic == "medium"
+    op = pinned.operating_point
     rids_p = [pinned.submit([1 + i, 2, 3], max_new=2) for i in range(5)]
     done_p = pinned.run()
-    # the operating point only steers accounting, never the tokens
+    assert pinned.operating_point is op and pinned.traffic == "medium"
     for a, b in zip(rids, rids_p):
         assert done[a].generated == done_p[b].generated
 
@@ -479,3 +481,95 @@ def test_chip_smoke_logits_check(decode_offset):
     assert err.shape == (len(prompt) + len(generated) - 1,)
     assert err[:len(prompt)].max() <= cs.LOGITS_TOL
     assert (err.max() <= cs.LOGITS_TOL) == (decode_offset == 0)
+
+
+# --- host clock, step log, program names --------------------------------------
+
+def test_engine_step_log_one_record_per_step(monkeypatch):
+    """Every ``step()`` call, empty ones included, appends one record with
+    ordered stamps, the program it ran and the prompt tokens it took; the
+    process-wide log is bounded."""
+    from collections import deque
+    from repro.serve import telemetry
+    assert telemetry.LOG.maxlen == telemetry.MAXLEN == 65536
+    monkeypatch.setattr(telemetry, "LOG", deque(maxlen=5))
+    cfg = _cfg()
+    eng = ServeEngine(init_model_params(KEY, cfg), cfg, RC, batch_slots=2,
+                      max_len=64, prefill_chunk=4)
+    eng.submit(list(range(1, 7)), max_new=2)      # 6 prompt tokens: 4 + 2
+    kinds, prompt_tokens = [], []
+    for _ in range(4):
+        eng.step()
+        rec = telemetry.LOG[-1]
+        kinds.append(rec.kind)
+        prompt_tokens.append(rec.prompt_tokens)
+        assert rec.t0 <= rec.refill <= rec.assemble <= rec.dispatch \
+            <= rec.wait <= rec.fetch <= rec.t1
+        assert sum(telemetry.phase_seconds(rec).values()) == \
+            pytest.approx(rec.t1 - rec.t0)
+    assert kinds == ["prefill", "prefill", "decode", "empty"]
+    assert prompt_tokens == [4, 2, 0, 0]
+    assert [r.width for r in telemetry.LOG] == [4, 2, 1, 0]
+    assert [r.live for r in telemetry.LOG] == [1, 1, 1, 0]
+    assert [r.placed for r in telemetry.LOG] == [1, 0, 0, 0]
+    for _ in range(3):
+        eng.step()
+    assert len(telemetry.LOG) == 5                # bounded at maxlen
+    assert all(r.kind == "empty" for r in list(telemetry.LOG)[-3:])
+    t = telemetry.LOG[-1].t1
+    assert telemetry.steps(t_from=t) == []
+    assert telemetry.steps(t_to=t) == list(telemetry.LOG)
+
+
+def test_step_log_summary_reads_every_counter():
+    """The operator's one-line summary counts each kind of step and reads
+    the live slots, refills, chunk width and prompt tokens of the log."""
+    from repro.serve import telemetry
+
+    def rec(t0, kind, width, prompt_tokens, live, placed):
+        return telemetry.StepRecord(*(t0 + 1e-3 * k for k in range(7)),
+                                    kind, width, prompt_tokens, live, placed)
+    line = telemetry.summary([rec(0.0, "prefill", 8, 12, 3, 2),
+                              rec(1.0, "prefill", 4, 4, 4, 1),
+                              rec(2.0, "decode", 1, 0, 4, 0),
+                              rec(3.0, "empty", 0, 0, 0, 0)])
+    assert line.startswith("4 steps (2 prefill, 1 decode, 1 empty); "
+                           "3.67 live slots per busy step, 3 refills; "
+                           "prefill step width 6.0, 8.0 prompt tokens; ")
+    assert "serve.wait 1.000" in line and "serve.commit 1.000" in line
+    assert telemetry.summary([]).startswith("0 steps (0 prefill")
+
+
+def test_engine_lifecycle_stamps_on_the_host_clock():
+    """Every lifecycle stamp the engine writes is ``perf_counter`` seconds
+    inside the caller's own readings, in lifecycle order."""
+    cfg = _cfg()
+    eng = ServeEngine(init_model_params(KEY, cfg), cfg, RC, batch_slots=2,
+                      max_len=64)
+    t_before = time.perf_counter()
+    rids = [eng.submit([1 + i, 2, 3, 4], max_new=3) for i in range(3)]
+    eng.run()
+    t_after = time.perf_counter()
+    for rid in rids:
+        r = eng.sched.requests[rid]
+        assert t_before <= r.arrival <= r.admit_time <= r.prefill_end \
+            <= r.first_token <= r.finish <= t_after
+    # the third request waited for a slot: admitted after the first step
+    third = eng.sched.requests[rids[2]]
+    assert third.admit_time > eng.sched.requests[rids[0]].first_token
+
+
+def test_engine_step_programs_are_named():
+    """The jitted step programs lower as ``jit_decode_step`` and
+    ``jit_prefill_step``, so a device trace names them."""
+    cfg = _cfg()
+    eng = ServeEngine(init_model_params(KEY, cfg), cfg, RC, batch_slots=2,
+                      max_len=32)
+    n = eng.sched.n_slots
+    dec = eng.decode_fn.lower(eng.params, eng.cache,
+                              {"tokens": jnp.zeros((n, 1), jnp.int32)})
+    pre = eng.prefill_fn(2).lower(
+        eng.params, eng.cache, {"tokens": jnp.zeros((n, 2), jnp.int32),
+                                "n_tokens": jnp.zeros((n,), jnp.int32)})
+    assert "module @jit_decode_step " in dec.as_text()
+    assert "module @jit_prefill_step " in pre.as_text()
