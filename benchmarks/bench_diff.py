@@ -21,8 +21,8 @@ Two classes of check, matched to how reproducible each metric is:
   machine, so the committed values are only checked against static floors:
   the gate catches a regression that slipped into a committed artifact, not
   machine noise.  ``BENCH_serve_prefill.json`` additionally must assert
-  bit-exactness (its ``headline.bit_exact`` flag) and a bounded chunk-jit
-  cache.
+  that the chunked path matches the token path (its
+  ``headline.matches_token_path`` flag) and a bounded chunk-jit cache.
 
 A per-metric delta table prints to stdout and, when ``$GITHUB_STEP_SUMMARY``
 is set, is appended there so the drift is visible on the job page without
@@ -194,8 +194,9 @@ def check_cluster_strong(rows, problems):
 def check_serve_prefill(rows, problems):
     """Committed live-engine chunked-prefill gate artifact.  Wall-clock and
     cycles TTFT gains are floor-checked against the embedded bar (the wall
-    number is machine-dependent, so no exact compare); the bit-exactness
-    flag and the bounded chunk-jit-cache count must hold outright."""
+    number is machine-dependent, so no exact compare); the flag that it
+    matches the token path and the bounded chunk-jit-cache count must hold
+    outright."""
     art = _load("BENCH_serve_prefill.json")
     head = art["headline"]
     bar = head["min_required"]
@@ -207,13 +208,13 @@ def check_serve_prefill(rows, problems):
                 f"fell below the {bar} floor")
         rows.append(_row(f"serve_prefill.headline.{key}", bar, head[key],
                          f">= {bar}", ok))
-    ok = head["bit_exact"] is True
+    ok = head["matches_token_path"] is True
     if not ok:
         problems.append(
             "BENCH_serve_prefill.json: chunked prefill was committed "
-            "without bit-exactness vs the token-by-token path")
-    rows.append(_row("serve_prefill.headline.bit_exact", True,
-                     head["bit_exact"], "== True", ok))
+            "without matching the token-by-token path")
+    rows.append(_row("serve_prefill.headline.matches_token_path", True,
+                     head["matches_token_path"], "== True", ok))
     compiles, bound = art["prefill_compiles"], art["max_prefill_compiles"]
     ok = compiles <= bound
     if not ok:

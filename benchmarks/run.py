@@ -65,7 +65,7 @@ def main(argv=None) -> None:
             ("serve SLO smoke (continuous vs wave batching under "
              "trace-driven load)", serve_slo.smoke),
             ("serve prefill smoke (live chunked prefill vs token-by-token "
-             "TTFT, bit-exact)", serve_slo.prefill_smoke),
+             "TTFT, matching tokens)", serve_slo.prefill_smoke),
         ])
         return
 
@@ -91,7 +91,7 @@ def main(argv=None) -> None:
         ("front diff (committed Pareto-front drift gate)", front_diff.main),
         ("serve SLO (continuous vs wave batching under trace-driven load)",
          serve_slo.main),
-        ("serve prefill (live chunked prefill >=2x TTFT gate, bit-exact)",
+        ("serve prefill (live chunked prefill >=2x TTFT gate, matching)",
          serve_slo.prefill_main),
         ("kernels (interpret-mode micro-bench)", kernel_bench.main),
         ("collective policy (bulk vs ring)", collective_policy.main),

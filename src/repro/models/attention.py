@@ -1,4 +1,5 @@
-"""Attention layers: GQA (full/causal/local-window), MLA, and decode paths.
+"""Attention layers: GQA (full/causal/local-window), MLA, and the decode and
+per-slot chunk paths over a serving cache.
 
 All sequence-level attention goes through :func:`flash_attention_ref` — a
 blockwise online-softmax implementation in pure jnp (the oracle for the
@@ -240,6 +241,56 @@ def gqa_decode(p, x: jax.Array, cfg: ModelConfig, k_cache, v_cache,
     return out, k_cache, v_cache
 
 
+def _chunk_positions(length: jax.Array, n_tokens: jax.Array, C: int, T: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """Cache position of each chunk column, ``len[i] + j`` (B, C), and the
+    row each column writes: its position where ``j < n_tokens[i]``, else
+    ``T``, which a ``mode="drop"`` scatter discards.  (A
+    ``dynamic_update_slice`` would clamp a chunk that ends at ``T`` back
+    onto valid rows.)"""
+    pos = length[:, None] + jnp.arange(C, dtype=jnp.int32)
+    rows = jnp.where(jnp.arange(C) < n_tokens[:, None], pos, T)
+    return pos, rows
+
+
+def gqa_chunk(p, x: jax.Array, cfg: ModelConfig, k_cache, v_cache,
+              length: jax.Array, n_tokens: jax.Array):
+    """A chunk of C tokens per slot in one pass.  x: (B, C, d); caches
+    (B, Hkv, T, hd); ``length`` and ``n_tokens`` (B,).  Column ``j`` of
+    slot ``i`` sits at position ``length[i] + j`` and writes its K/V row
+    there only if ``j < n_tokens[i]``; then each query attends to the cache
+    rows up to its own position, with :func:`gqa_decode`'s f32 scores and
+    masking.  Returns (out (B, C, d), new_k_cache, new_v_cache)."""
+    B, C, _ = x.shape
+    _, Hkv, T, hd = k_cache.shape
+    pos, rows = _chunk_positions(length, n_tokens, C, T)
+    q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"])
+    k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"])
+    v = jnp.einsum("bsd,dhk->bhsk", x, p["wv"])
+    if cfg.rope:
+        cos, sin = rope_angles(pos, cfg.resolved_head_dim, cfg.rope_theta)
+        q = apply_rope(q, cos[:, None], sin[:, None])
+        k = apply_rope(k, cos[:, None], sin[:, None])
+    b = jnp.arange(B)[:, None]
+    with jax.named_scope("cache_write"):
+        # advanced indices around a slice: the update is (B, C, Hkv, hd)
+        k_cache = k_cache.at[b, :, rows].set(
+            k.transpose(0, 2, 1, 3).astype(k_cache.dtype), mode="drop")
+        v_cache = v_cache.at[b, :, rows].set(
+            v.transpose(0, 2, 1, 3).astype(v_cache.dtype), mode="drop")
+    G = q.shape[1] // Hkv
+    qg = q.reshape(B, Hkv, G, C, hd)
+    s = jnp.einsum("bhgcd,bhtd->bhgct", qg.astype(jnp.float32),
+                   k_cache.astype(jnp.float32)) * (1.0 / math.sqrt(hd))
+    mask = jnp.arange(T)[None, None] <= pos[:, :, None]          # (B, C, T)
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    pattn = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgct,bhtd->bhgcd", pattn, v_cache.astype(jnp.float32))
+    o = o.reshape(B, Hkv * G, C, hd).astype(v_cache.dtype)
+    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+    return out, k_cache, v_cache
+
+
 # ---------------------------------------------------------------------------
 # MLA (Multi-head Latent Attention, MiniCPM3/DeepSeek-style)
 # ---------------------------------------------------------------------------
@@ -336,6 +387,50 @@ def mla_decode(p, x: jax.Array, cfg: ModelConfig, latent_cache, rope_cache,
     T = latent_cache.shape[1]
     mask = jnp.arange(T)[None] <= length[:, None]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
+    pattn = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bhst,btr->bhsr", pattn,
+                       latent_cache.astype(jnp.float32))
+    o = jnp.einsum("bhsr,rhk->bhsk", o_lat.astype(x.dtype), p["wuv"])
+    out = jnp.einsum("bhsk,hkd->bsd", o, p["wo"])
+    return out, latent_cache, rope_cache
+
+
+def mla_chunk(p, x: jax.Array, cfg: ModelConfig, latent_cache, rope_cache,
+              length: jax.Array, n_tokens: jax.Array):
+    """A chunk of C tokens per slot in one pass, in :func:`mla_decode`'s
+    absorbed form.  x: (B, C, d); caches (B, T, r) and (B, T, rope_dim);
+    ``length`` and ``n_tokens`` (B,).  Column ``j`` of slot ``i`` writes its
+    latent and k_rope rows at ``length[i] + j`` only if ``j < n_tokens[i]``,
+    and attends to the cache rows up to that position."""
+    m = cfg.mla
+    B, C, _ = x.shape
+    T = latent_cache.shape[1]
+    pos, rows = _chunk_positions(length, n_tokens, C, T)
+    cos, sin = rope_angles(pos, m.qk_rope_head_dim, cfg.rope_theta)
+    cq = rms_norm(x @ p["wdq"], p["q_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhk->bhsk", cq, p["wuq"])
+    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+    ckv = x @ p["wdkv"]
+    lat_t, k_rope_t = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
+    lat_t = rms_norm(lat_t, p["kv_norm"], cfg.norm_eps)
+    q_rope = apply_rope(q_rope, cos[:, None], sin[:, None])
+    k_rope_t = apply_rope(k_rope_t, cos, sin)
+
+    b = jnp.arange(B)[:, None]
+    with jax.named_scope("cache_write"):
+        latent_cache = latent_cache.at[b, rows].set(
+            lat_t.astype(latent_cache.dtype), mode="drop")
+        rope_cache = rope_cache.at[b, rows].set(
+            k_rope_t.astype(rope_cache.dtype), mode="drop")
+
+    q_eff = jnp.einsum("bhsk,rhk->bhsr", q_nope, p["wuk"])    # (B,H,C,r)
+    s = (jnp.einsum("bhsr,btr->bhst", q_eff.astype(jnp.float32),
+                    latent_cache.astype(jnp.float32))
+         + jnp.einsum("bhsk,btk->bhst", q_rope.astype(jnp.float32),
+                      rope_cache.astype(jnp.float32)))
+    s = s / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    mask = jnp.arange(T)[None, None] <= pos[:, :, None]          # (B, C, T)
+    s = jnp.where(mask[:, None], s, NEG_INF)
     pattn = jax.nn.softmax(s, axis=-1)
     o_lat = jnp.einsum("bhst,btr->bhsr", pattn,
                        latent_cache.astype(jnp.float32))

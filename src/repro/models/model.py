@@ -450,18 +450,66 @@ def prefill_step(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
     Returns ``(logits, cache)`` where ``logits[i]`` is the next-token
     distribution after slot ``i``'s **last valid column** — for a decoding
     slot that is the ordinary decode logits; for a slot whose prefill
-    completes inside this chunk it is the first-generated-token logits.
+    completes inside this chunk it is the first-generated-token logits —
+    and zeros where ``n_tokens[i] == 0``.  ``cache["len"]`` advances by
+    ``n_tokens``.
 
-    Bit-exactness with the token-by-token path is by construction: the
-    chunk columns are advanced by ``lax.scan`` over the *same*
-    :func:`decode_step` body (per-sequence positions, attention/SSM/RG-LRU
-    cache writes included), with a per-slot mask selecting whether the
-    column's update lands — so one ``(B, C)`` call produces exactly the
-    tokens and final cache rows that C single-token calls would, while the
-    per-step host dispatch, device sync, and scheduling overhead are paid
-    once per chunk instead of once per token (the ``PREFILL_FRACTION``
-    discount the serve cost model charges prompt tokens).
+    Attention families (dense GQA, MLA, dense-FFN or MoE blocks) run the
+    whole ``(B, C)`` chunk through the layer stack in one pass, so each
+    weight and the cache are read once per chunk: column ``j`` of slot ``i``
+    sits at position ``len[i] + j``, writes its K/V (or latent) row there
+    if ``j < n_tokens[i]``, and attends to the rows up to its position.
+    Against C :func:`decode_step` calls this gives the same greedy tokens
+    and the same logits and written rows up to float rounding (matmuls over
+    C rows may sum in another order); rows of columns that are not written,
+    and every row of a slot with ``n_tokens == 0``, are left bit for bit.
+    Recurrent families (``ssm``, ``hybrid``) carry state that a parallel
+    chunk would have to scan, so they keep :func:`_prefill_scan`.
     """
+    if cfg.family in ("ssm", "hybrid"):
+        return _prefill_scan(params, cache, batch, cfg, rc)
+    tokens, n_tokens = batch["tokens"], batch["n_tokens"]
+    dtype = jnp.dtype(rc.dtype)
+    x = params["embed"][tokens].astype(dtype)
+    length = cache["len"]
+    cast = lambda t: jax.tree_util.tree_map(lambda a: a.astype(dtype)
+                                            if a.dtype == jnp.float32 else a, t)
+    names, attend = ((("latent", "rope"), attn.mla_chunk) if cfg.mla
+                     else (("k", "v"), attn.gqa_chunk))
+
+    def body(h, sl):
+        bp, c0, c1 = sl
+        bp = cast(bp)
+        hn = rms_norm(h, bp["ln1"], cfg.norm_eps)
+        with jax.named_scope("attention"):
+            y, c0, c1 = attend(bp["attn"], hn, cfg, c0, c1, length, n_tokens)
+        h = h + y
+        hn = rms_norm(h, bp["ln2"], cfg.norm_eps)
+        with jax.named_scope("ffn"):
+            y = (moe_mod.moe_apply(bp["ffn"], hn, cfg) if cfg.moe
+                 else ffn_apply(bp["ffn"], hn, cfg.ffn_act))
+        return h + y, (c0, c1)
+    x, (c0, c1) = _stack_scan(
+        body, x, (params["blocks"], cache[names[0]], cache[names[1]]), rc)
+    cache = {**cache, names[0]: c0, names[1]: c1, "len": length + n_tokens}
+
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    last = jnp.maximum(n_tokens - 1, 0)
+    x = jnp.take_along_axis(x, last[:, None, None], axis=1)       # (B, 1, d)
+    head = params["embed"] if cfg.tie_embeddings else params["head"]
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x, head.astype(dtype))[:, 0]
+    logits = jnp.where(n_tokens[:, None] > 0, logits.astype(jnp.float32), 0.0)
+    return logits, cache
+
+
+def _prefill_scan(params: Pytree, cache: Pytree, batch: Dict[str, jax.Array],
+                  cfg: ModelConfig, rc: RunConfig
+                  ) -> Tuple[jax.Array, Pytree]:
+    """:func:`prefill_step` for recurrent families: ``lax.scan`` of the
+    whole :func:`decode_step` over the chunk's columns, each column's cache
+    update kept only for the slots it is active in.  Bit-exact with C
+    token-by-token calls."""
     tokens, n_tokens = batch["tokens"], batch["n_tokens"]
     B, C = tokens.shape
 

@@ -15,13 +15,15 @@ Prompt ingestion is chunked: any step with a prefilling slot runs the
 jitted :func:`~repro.models.model.prefill_step`, feeding up to
 ``prefill_chunk`` prompt tokens per prefilling slot per call while
 neighbouring slots mid-decode ride along in the same batch with a one-token
-chunk — bit-exact with the token-by-token path by construction (the chunk
-kernel scans the same ``decode_step`` body over its columns).  Chunk widths
-are bucketed to powers of two so the jit cache holds at most
-``log2(prefill_chunk) + 1`` programs (``prefill_compiles`` counts them, and
-:meth:`ServeEngine.warmup` compiles them all before serving);
-``prefill="token"`` keeps the old one-token-per-step ingestion as the
-measurable TTFT baseline.
+chunk.  For attention models the chunk is one pass through the layer stack,
+so it gives the token-by-token path's greedy tokens, and its logits and
+cache rows up to float rounding; slots without a token in the chunk are
+left exact (recurrent models scan ``decode_step`` over the columns and stay
+bit-exact).  Chunk widths are bucketed to powers of two so the jit cache
+holds at most ``log2(prefill_chunk) + 1`` programs (``prefill_compiles``
+counts them, and :meth:`ServeEngine.warmup` compiles them all before
+serving); ``prefill="token"`` keeps the old one-token-per-step ingestion as
+the measurable TTFT baseline.
 
 The engine runs on the host clock: every request lifecycle stamp is
 ``time.perf_counter()`` seconds.  Each phase of :meth:`ServeEngine.step` is

@@ -2,12 +2,13 @@
 slot refill, straggler-aware host dispatch, SLO accounting on the
 virtual-time simulation, the live engine's continuous-batching equivalence
 (a mid-run admitted request decodes the same tokens as on a fresh engine),
-chunked-prefill bit-exactness on mixed-phase batches, pinned-traffic
+chunked prefill matching the token path on mixed-phase batches, pinned-traffic
 operating points, and the engine's host-clock stamps and step log."""
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.config import RunConfig
@@ -288,11 +289,15 @@ def _slot_rows(cache, i):
 
 def test_engine_chunked_prefill_mixed_phase_bit_exact():
     """One slot mid-prefill-chunk while its neighbour decodes: the chunked
-    engine's generated tokens and each request's cache rows *at its
-    completion step* are bit-exact with the token-by-token reference.
+    engine's generated tokens equal the token-by-token reference's, and
+    each request's cache rows *at its completion step* match it: rows the
+    request wrote within float rounding (a chunk is one parallel pass, not
+    C decode steps), the rows past its length and its length exactly.
     (Rows are snapshotted at completion: once a slot frees, later steps may
     overwrite it with junk that the next refill zeroes — comparing
-    end-of-run rows of freed slots would compare that junk.)"""
+    end-of-run rows of freed slots would compare that junk.)  The name is
+    older than the parallel chunk: the bit-exact part is now the rows the
+    request did not write."""
     cfg = _cfg()
     params = init_model_params(KEY, cfg)
     # rid 0: short prompt, decodes while rid 1 is still chunk-prefilling
@@ -323,9 +328,15 @@ def test_engine_chunked_prefill_mixed_phase_bit_exact():
             token.finished[rid].generated
         rows_c, rows_t = snaps_c[rid], snaps_t[rid]
         assert set(rows_c) == set(rows_t)
-        for k in rows_c:
-            assert bool(jnp.array_equal(rows_c[k], rows_t[k])), \
-                f"rid {rid} cache leaf {k!r} diverged"
+        n = int(rows_c["len"])
+        assert n == int(rows_t["len"])
+        for k in set(rows_c) - {"len"}:
+            # (L, Hkv, T, hd): positions below the length were written
+            c, t = np.asarray(rows_c[k]), np.asarray(rows_t[k])
+            np.testing.assert_allclose(c[:, :, :n], t[:, :, :n], rtol=1e-5,
+                                       atol=1e-5, err_msg=f"rid {rid} {k!r}")
+            assert np.array_equal(c[:, :, n:], t[:, :, n:]), \
+                f"rid {rid} cache leaf {k!r} past its length"
     # the chunked run actually took fewer engine steps (that is the point)
     assert chunked._n_steps < token._n_steps
 

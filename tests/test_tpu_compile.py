@@ -1,5 +1,6 @@
 """Compiles for a described TPU v5e, with no chip attached: the five Pallas
-kernels at real widths and phi3-mini-3.8b's serve steps at full depth.
+kernels at real widths, and the serve steps of phi3-mini-3.8b and
+minicpm3-4b at full depth.
 
 Nothing runs.  A compile the TPU compiler refuses — an unsupported
 lowering, a kernel over its fast memory, a program over the chip's HBM —
@@ -22,8 +23,10 @@ from repro.kernels import (flash_attention, moe_gemm, queue_matmul,
 from repro.models.model import cache_spec, decode_step, param_shapes, \
     prefill_step
 
-#: the serve size chip_smoke.py runs: the largest at which prefill_step fits
+#: the serve size chip_smoke.py runs for phi3-mini-3.8b
 SLOTS, MAX_LEN, CHUNK = 4, 1024, 8
+#: minicpm3-4b's slots in the benchmark's ``minicpm3.chat`` cell
+MINICPM3_SLOTS = 16
 
 
 @pytest.fixture(scope="module")
@@ -76,21 +79,39 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("step", ["decode_step", "prefill_step"])
-def test_phi3_serve_step_compiles_for_v5e(one_chip, step):
-    """phi3-mini-3.8b at published widths and full depth, bf16, as the
-    engine runs it: the compiler refuses a program over the chip's HBM."""
-    cfg = get_config("phi3-mini-3.8b")
+def _serve_step(sharding, arch, slots, step):
+    """``step`` of ``arch`` at published widths and full depth, bf16, as the
+    engine runs it with ``slots`` x ``MAX_LEN``, compiled for one described
+    chip: the compiler refuses a program over the chip's HBM."""
+    cfg = get_config(arch)
     rc = RunConfig(dtype="bfloat16", param_dtype="bfloat16", remat=False)
-    params = _on(one_chip, param_shapes(cfg, BF16))
-    cache = _on(one_chip, cache_spec(cfg, SLOTS, MAX_LEN, BF16))
+    params = _on(sharding, param_shapes(cfg, BF16))
+    cache = _on(sharding, cache_spec(cfg, slots, MAX_LEN, BF16))
     width = 1 if step == "decode_step" else CHUNK
-    batch = {"tokens": jax.ShapeDtypeStruct((SLOTS, width), jnp.int32)}
+    batch = {"tokens": jax.ShapeDtypeStruct((slots, width), jnp.int32)}
     if step == "prefill_step":
-        batch["n_tokens"] = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+        batch["n_tokens"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
     fn = {"decode_step": decode_step, "prefill_step": prefill_step}[step]
     compiled = jax.jit(partial(fn, cfg=cfg, rc=rc)).lower(
-        params, cache, _on(one_chip, batch)).compile()
+        params, cache, _on(sharding, batch)).compile()
     held = sum(a.size * a.dtype.itemsize
                for a in jax.tree.leaves((params, cache)))
-    assert compiled.memory_analysis().argument_size_in_bytes >= held
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= held
+    return memory
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_step"])
+def test_phi3_serve_step_compiles_for_v5e(one_chip, step):
+    """phi3-mini-3.8b at 4 x 1024.  ``prefill_step`` is one pass over the
+    chunk, so its temporaries stay under two 1.61 GB caches (0 bytes with
+    libtpu 0.0.34; a column scan of decode_step needs 6.44 GB)."""
+    memory = _serve_step(one_chip, "phi3-mini-3.8b", SLOTS, step)
+    if step == "prefill_step":
+        assert memory.temp_size_in_bytes < 3.2e9
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_step"])
+def test_minicpm3_serve_step_compiles_for_v5e(one_chip, step):
+    """minicpm3-4b (MLA) at the benchmark's 16 x 1024."""
+    _serve_step(one_chip, "minicpm3-4b", MINICPM3_SLOTS, step)
