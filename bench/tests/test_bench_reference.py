@@ -22,7 +22,7 @@ def test_reference_matches_program_forward(which):
     rc = RunConfig(dtype="float32", param_dtype="float32", remat=False)
     with jax.default_matmul_precision("highest"):
         want = forward(w, {"tokens": tokens}, m, rc)
-    h = _hidden(w, tokens, _static_cfg(cfg), which, False, m.n_layers)
+    h = _hidden(w, tokens, _static_cfg(cfg), which, False)
     got = mm_f32(rms_norm(h, w["final_norm"], m.norm_eps), w["head"].T)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     assert float(jnp.std(want)) > 0.05       # the logits are not flat
